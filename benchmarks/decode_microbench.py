@@ -10,11 +10,11 @@ paged decode-attention impls (``attn_decode_impl`` in
 * ``decode_step``         — per-token cost of a scanned decode dispatch
                             (the serving inner loop, reported per step).
 
-Each row also carries an estimated bytes-moved figure and its HBM roofline
-join against ``benchmarks/roofline.py``'s hardware model (time the bytes
-would take at ``HBM_BW``, and that model time as a fraction of measured
-wall-clock — meaningful on TPU; on CPU the fraction is only a shape-level
-sanity signal).  Byte estimates count the dominant streams — parameter
+Each row also carries an estimated bytes-moved figure.  On a TPU the row
+joins it against the chip's HBM peak (``roofline.peaks(device_kind)``: the
+time the bytes would take, and that time as a fraction of measured
+wall-clock); elsewhere there is no device roofline and those fields are
+None.  Byte estimates count the dominant streams — parameter
 bytes + the slot-linear attention KV view per decode step, measured from
 the engine's actual cache shapes via ``jax.eval_shape`` — not every
 activation.
@@ -49,7 +49,7 @@ import numpy as np
 sys.path.insert(0, "src")
 sys.path.insert(0, "benchmarks")
 
-from roofline import HBM_BW  # noqa: E402
+import roofline  # noqa: E402
 
 
 def best_of(fn, iters: int, warmup: int = 3) -> float:
@@ -118,6 +118,7 @@ def build_engine(args, impl: str, cache_quant=None, pool_blocks=None):
 def bench_impl(args, impl: str, prompts, span,
                cache_quant=None) -> dict[str, dict]:
     eng = build_engine(args, impl, cache_quant=cache_quant)
+    hbm_bw = device_hbm_bw()
     B = args.batch
     p_bytes = tree_bytes(eng.params)
 
@@ -160,14 +161,25 @@ def bench_impl(args, impl: str, prompts, span,
         if args.profile:
             with jax.profiler.trace(f"{args.profile}/{impl}_{name}"):
                 fn()
-        model_sec = nbytes / HBM_BW
+        model_sec = nbytes / hbm_bw if hbm_bw else None
         out[name] = {
             "ms": sec / per * 1e3,
             "est_mb": nbytes / per / 1e6,
-            "hbm_model_ms": model_sec / per * 1e3,
-            "hbm_frac": model_sec / sec if sec else 0.0,
+            "hbm_model_ms": (model_sec / per * 1e3
+                             if model_sec is not None else None),
+            "hbm_frac": (model_sec / sec if model_sec is not None and sec
+                         else None),
         }
     return out
+
+
+def device_hbm_bw() -> float | None:
+    """HBM peak of the chip this process runs on; None off-TPU (a CPU run
+    has no device roofline).  An unknown TPU raises (roofline.peaks)."""
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return None
+    return roofline.peaks(dev.device_kind)["hbm_bw"]
 
 
 def session_density(args) -> dict[str, dict]:
@@ -257,16 +269,22 @@ def main(argv=None) -> int:
             args, "kernel", prompts, span, cache_quant=args.quant)
         impls.append((f"kernel@{args.quant}", f"kernel@{args.quant}"))
 
+    dev = jax.devices()[0]
+    print(f"device: {dev.platform} {dev.device_kind} x{len(jax.devices())}")
     hdr = (f"{'phase':<22}{'impl':<14}{'ms/call':>10}{'est MB':>10}"
            f"{'HBM-model ms':>14}{'frac':>8}")
     print(hdr)
     print("-" * len(hdr))
+
+    def num(x, w, p):
+        return f"{'n/a':>{w}}" if x is None else f"{x:>{w}.{p}f}"
+
     for name in ("prefill", "continuation_insert", "decode_step"):
         for key, label in impls:
             r = results[key][name]
             print(f"{name:<22}{label:<14}{r['ms']:>10.3f}"
                   f"{r['est_mb']:>10.2f}"
-                  f"{r['hbm_model_ms']:>14.4f}{r['hbm_frac']:>8.3f}")
+                  f"{num(r['hbm_model_ms'], 14, 4)}{num(r['hbm_frac'], 8, 3)}")
     ratio = (results["kernel"]["decode_step"]["ms"]
              / results["gather"]["decode_step"]["ms"])
     print(f"\nkernel_vs_gather_paged_decode: {ratio:.3f} "

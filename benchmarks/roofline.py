@@ -1,7 +1,8 @@
 """Roofline analysis from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI
-per chip.  Per (arch x shape x mesh):
+Hardware model: per-chip peaks keyed by ``jax.Device.device_kind``
+(:data:`PEAKS`).  The dry-run sweep models a TPU v5e pod
+(:data:`DRYRUN_DEVICE_KIND`).  Per (arch x shape x mesh):
 
   compute_term    = corrected FLOPs/device   / peak_flops
   memory_term     = corrected bytes/device   / hbm_bw
@@ -17,9 +18,33 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Published per-chip peaks, keyed by the device_kind JAX reports.  Source:
+# Google Cloud documentation, "TPU v5e" (system architecture table): 197
+# TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# inter-chip interconnect per chip over 4 ICI links (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "int8_ops": 393e12,
+                    "hbm_bytes": 16e9, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> dict:
+    """Peaks of one chip of ``device_kind``; a device not in :data:`PEAKS`
+    is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}: add them to roofline.PEAKS with "
+                       "their source") from None
+
+
+_DRYRUN = peaks(DRYRUN_DEVICE_KIND)
+PEAK_FLOPS = _DRYRUN["bf16_flops"]
+HBM_BW = _DRYRUN["hbm_bw"]
+LINK_BW = _DRYRUN["link_bw"]
 
 CHIPS = {"single": 256, "multi": 512}
 

@@ -20,6 +20,9 @@ the mesh-sharded serving engine — see docs/SHARDING.md):
 * ``constrain(x, logical, mesh, rules)`` — ``with_sharding_constraint``
   by logical names; a no-op when ``mesh`` is None, which is how the
   serving/runtime code stays bit-identical off-mesh.
+* ``kernel_split(mesh, batch, heads)`` — how a per-(row, head) Pallas
+  kernel splits under ``shard_map`` (Mosaic kernels are not partitioned
+  automatically).
 * ``RULE_SETS``: ``default`` (training FSDP x TP), ``sp`` (sequence-
   parallel prefill), ``serve`` (weights replicated over 'data', pure TP —
   decode never re-gathers FSDP shards).
@@ -266,3 +269,19 @@ def constrain(x: jax.Array, logical: Sequence[str | None], mesh: Mesh | None,
     rules = rules or DEFAULT_RULES
     spec = spec_for(x.shape, logical, mesh, rules.act_rules)
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+
+
+def kernel_split(mesh: Mesh, batch: int, heads: int) -> tuple[Any, Any]:
+    """(batch entry, head entry) of the ``shard_map`` specs for a kernel
+    that is independent per batch row and per (KV) head: rows split over
+    the mesh's pod/data axes and heads over 'model', each only where it
+    divides — otherwise that dim is replicated and every shard computes
+    all of it."""
+    daxes = tuple(a for a in ("pod", "data") if a in mesh.shape)
+    b = None
+    if daxes and batch % _axis_size(mesh, daxes) == 0:
+        b = daxes if len(daxes) > 1 else daxes[0]
+    h = None
+    if "model" in mesh.shape and heads % mesh.shape["model"] == 0:
+        h = "model"
+    return b, h

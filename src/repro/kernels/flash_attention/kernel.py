@@ -7,6 +7,14 @@ is ``pl.when``-gated so no MXU work is issued) and the output tile is
 written at the last *needed* block — the same block-skipping that makes a
 real TPU flash kernel ~2x over dense for causal.
 
+TPU tiling: a block's last two dims must be multiples of (8, 128) or span
+the whole array, so a one-head block of a (B, S, H, D) array is illegal.
+The wrappers move heads in front — (B, H, S, D), one transpose of the
+activations, O(S·H·D) against the kernel's O(S·T·H·D) — and tile
+(rows, D).  Position vectors are reshaped to one block per leading index
+(``(S/bq, bq, 1)`` columns for queries, ``(T/bk, 1, bk)`` rows for keys),
+so every position block spans its array's last two dims.
+
 Grid: (B, H, S/bq, T/bk), KV innermost.
 """
 
@@ -22,6 +30,38 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import blocking
 
 NEG_INF = -1e30
+
+
+def _online_update(s, v, m_ref, l_ref, acc_ref):
+    """Fold one (bq, bk) block of masked scores and its (bk, D) values into
+    the running max / sum / accumulator scratch ((bq, 1), (bq, 1),
+    (bq, D))."""
+    m_old, l_old = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_old, s.max(axis=1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_old - m_new)
+    l_ref[...] = l_old * corr + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+
+def _finish(o_ref, l_ref, acc_ref):
+    out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+    o_ref[...] = out.astype(o_ref.dtype)
+
+
+def _scratch(bq: int, D: int):
+    return [pltpu.VMEM((bq, 1), jnp.float32),        # running max
+            pltpu.VMEM((bq, 1), jnp.float32),        # running sum
+            pltpu.VMEM((bq, D), jnp.float32)]        # output accumulator
+
+
+def _head_major(q, k, v):
+    """(B, S, H, D) -> (B, H, S, D) for q and (B, T, K, D) -> (B, K, T, D)
+    for k/v: the layout whose (rows, D) tiles the TPU accepts."""
+    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.transpose(0, 2, 1, 3))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
@@ -48,9 +88,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
 
     @pl.when(needed)
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)       # (bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32)               # (bq, D)
+        k = k_ref[...].astype(jnp.float32)               # (bk, D)
+        v = v_ref[...].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -62,20 +102,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
             mask &= qpos - kpos < window
         s = jnp.where(mask, s, NEG_INF)
 
-        m_old, l_old = m_ref[...], l_ref[...]
-        m_new = jnp.maximum(m_old, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_old - m_new)
-        l_ref[...] = l_old * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        _online_update(s, v, m_ref, l_ref, acc_ref)
 
     @pl.when(j == last_needed)
     def _emit():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        _finish(o_ref, l_ref, acc_ref)
 
 
 def _flash_positions_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
@@ -96,33 +127,23 @@ def _flash_positions_kernel(q_ref, k_ref, v_ref, qp_ref, kp_ref, o_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)       # (bq, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)       # (bk, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    qp = qp_ref[0]                                   # (bq,) abs positions
-    kp = kp_ref[0]                                   # (bk,) -1 = empty slot
+    q = q_ref[...].astype(jnp.float32)               # (bq, D)
+    k = k_ref[...].astype(jnp.float32)               # (bk, D)
+    v = v_ref[...].astype(jnp.float32)
+    qp = qp_ref[...]                                 # (bq, 1) abs positions
+    kp = kp_ref[...]                                 # (1, bk) -1 = empty slot
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-    mask = jnp.broadcast_to((kp >= 0)[None, :], (bq, bk))
+    mask = jnp.broadcast_to(kp >= 0, (bq, bk))
     if causal:
-        mask &= qp[:, None] >= kp[None, :]
+        mask &= qp >= kp
     if window is not None:
-        mask &= qp[:, None] - kp[None, :] < window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_old, l_old = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_old, s.max(axis=1))
-    p = jnp.exp(s - m_new[:, None])
-    corr = jnp.exp(m_old - m_new)
-    l_ref[...] = l_old * corr + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-    m_ref[...] = m_new
+        mask &= qp - kp < window
+    _online_update(jnp.where(mask, s, NEG_INF), v, m_ref, l_ref, acc_ref)
 
     @pl.when(j == nk - 1)
     def _emit():
-        out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = out.astype(o_ref.dtype)
+        _finish(o_ref, l_ref, acc_ref)
 
 
 def flash_attention_positions_pallas(q: jax.Array, k: jax.Array,
@@ -140,30 +161,30 @@ def flash_attention_positions_pallas(q: jax.Array, k: jax.Array,
     G = H // K
     bq, bk = blocking.flash_blocks(S, T, bq, bk)
     assert S % bq == 0 and T % bk == 0
-    grid = (B, H, S // bq, T // bk)
+    nq, nk = S // bq, T // bk
     kern = functools.partial(
-        _flash_positions_kernel, bq=bq, bk=bk, nk=T // bk, causal=causal,
+        _flash_positions_kernel, bq=bq, bk=bk, nk=nk, causal=causal,
         window=window, scale=D ** -0.5)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, bq), lambda b, h, i, j: (0, i)),
-            pl.BlockSpec((1, bk), lambda b, h, i, j: (0, j)),
+            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((None, bq, 1), lambda b, h, i, j: (i, 0, 0)),
+            pl.BlockSpec((None, 1, bk), lambda b, h, i, j: (j, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, None, bq, D),
+                               lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        scratch_shapes=_scratch(bq, D),
         interpret=interpret,
-    )(q, k, v, q_positions.reshape(1, S).astype(jnp.int32),
-      kv_positions.reshape(1, T).astype(jnp.int32))
+    )(*_head_major(q, k, v), q_positions.reshape(nq, bq, 1).astype(jnp.int32),
+      kv_positions.reshape(nk, 1, bk).astype(jnp.int32))
+    return out.transpose(0, 2, 1, 3)
 
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -176,24 +197,23 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
     G = H // K
     bq, bk = blocking.flash_blocks(S, T, bq, bk)
     assert S % bq == 0 and T % bk == 0
-    grid = (B, H, S // bq, T // bk)
     kern = functools.partial(
         _flash_kernel, bq=bq, bk=bk, nk=T // bk, causal=causal,
         window=window, scale=D ** -0.5)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
-        grid=grid,
+        grid=(B, H, S // bq, T // bk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // G, 0)),
+            pl.BlockSpec((None, None, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, i, j: (b, h // G, j, 0)),
+            pl.BlockSpec((None, None, bk, D),
+                         lambda b, h, i, j: (b, h // G, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((None, None, bq, D),
+                               lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        scratch_shapes=_scratch(bq, D),
         interpret=interpret,
-    )(q, k, v)
+    )(*_head_major(q, k, v))
+    return out.transpose(0, 2, 1, 3)

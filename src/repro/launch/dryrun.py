@@ -184,7 +184,6 @@ def compile_cell(cfg, shape, mesh, rules=None, force_mb: int | None = None):
     dt = time.time() - t0
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, list) else ca    # jax<0.5 returns [dict]
     coll = parse_collectives(compiled.as_text())
     return {
         "compile_s": round(dt, 2),

@@ -12,12 +12,20 @@ jax device state).  Axes:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """Mesh with Auto axis types: the program places arrays through
+    ``with_sharding_constraint`` and jit in/out shardings, which act on
+    Auto axes only (``jax.make_mesh`` defaults to Explicit axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def elastic_mesh(model_parallel: int = 16, pods: int = 1):
@@ -33,9 +41,8 @@ def elastic_mesh(model_parallel: int = 16, pods: int = 1):
         pods -= 1
     data = rest // pods
     if pods > 1:
-        return jax.make_mesh((pods, data, model_parallel),
-                             ("pod", "data", "model"))
-    return jax.make_mesh((data, model_parallel), ("data", "model"))
+        return _mesh((pods, data, model_parallel), ("pod", "data", "model"))
+    return _mesh((data, model_parallel), ("data", "model"))
 
 
 def serving_mesh(model_parallel: int = 1):
@@ -51,8 +58,7 @@ def serving_mesh(model_parallel: int = 1):
     model_parallel = max(1, min(model_parallel, n))
     while n % model_parallel:
         model_parallel -= 1
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return _mesh((n // model_parallel, model_parallel), ("data", "model"))
 
 
 def data_shards(mesh) -> int:

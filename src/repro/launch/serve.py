@@ -174,9 +174,14 @@ def main():
                          "scales (implies --paged); ~1.9x the sessions per "
                          "pool byte — see docs/RUNTIME.md 'Quantized caches'")
     ap.add_argument("--compilation-cache-dir", default=None,
-                    help="persistent XLA compilation cache directory: a "
-                         "relaunched gateway skips every already-seen jit")
+                    help="persistent XLA compilation cache directory (a "
+                         "relaunched gateway skips every already-seen jit); "
+                         "a set JAX_COMPILATION_CACHE_DIR wins, the default "
+                         "is .jax_cache/ in the checkout")
     args = ap.parse_args()
+    from repro.compile_cache import enable_compilation_cache
+    print(f"[serve] compilation cache "
+          f"{enable_compilation_cache(args.compilation_cache_dir)}")
 
     mesh = None
     if args.model_parallel > 0:
@@ -193,8 +198,6 @@ def main():
                          attn_decode_impl=args.attn_decode_impl)
     if args.cache_quant is not None:
         engine_kw["cache_quant"] = args.cache_quant
-    if args.compilation_cache_dir is not None:
-        engine_kw["compilation_cache_dir"] = args.compilation_cache_dir
     gw, probe, cloud, world = build_gateway(args.train_steps, args.quorum,
                                             budget_total=args.budget,
                                             mesh=mesh, engine_kw=engine_kw)

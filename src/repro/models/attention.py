@@ -19,7 +19,9 @@ from typing import Any, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.sharding import constrain
+from jax.sharding import PartitionSpec as P
+
+from repro.distributed.sharding import constrain, kernel_split
 from repro.models import quant as Q
 from repro.models.common import (ModelConfig, ParamDef, apply_rope,
                                  norm_def, normal_init, rmsnorm, rope_angles,
@@ -182,23 +184,33 @@ def _project_qkv(p: dict, x: Array, cfg: ModelConfig, positions: Array):
 
 def _prefill_attention(q: Array, k: Array, v: Array, *, q_positions: Array,
                        kv_positions: Array, causal: bool, window: int | None,
-                       cfg: ModelConfig) -> Array:
+                       cfg: ModelConfig, mesh=None) -> Array:
     """Prefill-path attention with per-backend impl selection.
 
     ``cfg.attn_prefill_impl``: "chunked" = the XLA two-level-scan online
     softmax (the oracle); "flash" = the positions-mode Pallas flash kernel
     (interpret mode off-TPU); None = flash on TPU, chunked elsewhere —
     tier-1 CPU numerics are unchanged by default.  Training (``attn_block``)
-    always uses the chunked path: impl selection is serving-only.
+    always uses the chunked path: impl selection is serving-only.  On a
+    mesh the kernel runs under ``shard_map``, split by batch row and KV
+    head (``kernel_split``).
     """
     impl = cfg.attn_prefill_impl
     if impl is None:
         impl = "flash" if jax.default_backend() == "tpu" else "chunked"
     if impl == "flash":
         from repro.kernels.flash_attention.ops import flash_attention_positions
-        return flash_attention_positions(
-            q, k, v, q_positions=q_positions, kv_positions=kv_positions,
-            causal=causal, window=window)
+
+        def kern(q, k, v, qp, kp):
+            return flash_attention_positions(
+                q, k, v, q_positions=qp, kv_positions=kp, causal=causal,
+                window=window)
+        if mesh is not None:
+            b, h = kernel_split(mesh, q.shape[0], k.shape[2])
+            kern = jax.shard_map(
+                kern, mesh=mesh, out_specs=P(b, None, h),
+                in_specs=(P(b, None, h),) * 3 + (P(), P()), check_vma=False)
+        return kern(q, k, v, q_positions, kv_positions)
     return chunked_attention(q, k, v, q_positions=q_positions,
                              kv_positions=kv_positions, causal=causal,
                              window=window, q_block=cfg.attn_q_block,
@@ -206,7 +218,7 @@ def _prefill_attention(q: Array, k: Array, v: Array, *, q_positions: Array,
 
 
 def _attn_forward(p: dict, x: Array, cfg: ModelConfig, positions: Array,
-                  local: bool, *, prefill: bool = False
+                  local: bool, *, prefill: bool = False, mesh=None
                   ) -> tuple[Array, Array, Array]:
     """Shared full-sequence body -> (x + attn(x), k, v) — single source of
     truth for the training forward AND prefill so they cannot diverge.
@@ -217,7 +229,8 @@ def _attn_forward(p: dict, x: Array, cfg: ModelConfig, positions: Array,
     if prefill:
         out = _prefill_attention(
             q, k, v, q_positions=positions[0], kv_positions=positions[0],
-            causal=cfg.causal, window=cfg.window if local else None, cfg=cfg)
+            causal=cfg.causal, window=cfg.window if local else None, cfg=cfg,
+            mesh=mesh)
     else:
         out = chunked_attention(
             q, k, v,
@@ -297,7 +310,8 @@ def attn_prefill(p: dict, x: Array, cache: KVCache, positions: Array,
     logical-axis sharding so the bulk scatter does not un-shard it.
     """
     if not continuation:
-        out, k, v = _attn_forward(p, x, cfg, positions, local, prefill=True)
+        out, k, v = _attn_forward(p, x, cfg, positions, local, prefill=True,
+                                  mesh=mesh)
         return out, _scatter_kv(cache, k, v, positions, cfg, local,
                                 mesh, rules)
 
@@ -310,7 +324,8 @@ def attn_prefill(p: dict, x: Array, cache: KVCache, positions: Array,
     out = _prefill_attention(
         q, cache.k, cache.v,
         q_positions=positions[0], kv_positions=cache.pos[0],
-        causal=cfg.causal, window=cfg.window if local else None, cfg=cfg)
+        causal=cfg.causal, window=cfg.window if local else None, cfg=cfg,
+        mesh=mesh)
     y = jnp.einsum("bshk,hkd->bsd", out, p["wo"].astype(x.dtype))
     return x + y, cache
 
@@ -709,11 +724,27 @@ def attn_decode_paged(p: dict, x: Array, pool: KVCache, table: Array,
         # entries (>= N) stay sentinels for the flat pool (>= R * N).
         tbl = (jnp.where(table < N, table + base, R * N + 7)
                if stacked else table)
-        out = paged_decode_attention(
-            qr, kp, vp, pp, tbl, index,
-            window=cfg.window if local else None,
-            delta_k=delta.k, delta_v=delta.v, delta_pos=delta.pos, p0=p0,
-            k_scale=ksp, v_scale=vsp)
+        window = cfg.window if local else None
+
+        def kern(qr, kp, vp, pp, tbl, index, dk, dv, dpos, p0, *scales):
+            ks, vs = scales if quantized else (None, None)
+            return paged_decode_attention(
+                qr, kp, vp, pp, tbl, index, window=window, delta_k=dk,
+                delta_v=dv, delta_pos=dpos, p0=p0, k_scale=ks, v_scale=vs)
+        args = (qr, kp, vp, pp, tbl, index, delta.k, delta.v, delta.pos, p0)
+        if quantized:
+            args += (ksp, vsp)
+        if mesh is not None:
+            # a slot's table names pool blocks on every shard, so the pool
+            # block dim is whole on each device; rows and KV heads split
+            b, hd = kernel_split(mesh, B, K)
+            pool_spec = P(None, None, hd)
+            kern = jax.shard_map(
+                kern, mesh=mesh, out_specs=P(b, hd), check_vma=False,
+                in_specs=(P(b, hd), pool_spec, pool_spec, P(), P(b), P(b),
+                          P(b, None, hd), P(b, None, hd), P(b), P(b))
+                + (pool_spec,) * (2 if quantized else 0))
+        out = kern(*args)
         out = constrain(out.reshape(B, 1, cfg.num_heads, Dh),
                         ("act_batch", None, "act_heads", "act_head_dim"),
                         mesh, rules)

@@ -41,10 +41,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from repro.distributed.sharding import constrain
 from repro.models import quant as Q

@@ -387,11 +387,23 @@ def _cached_pass(params: dict, cfg: ModelConfig, tokens: Array, cache: list,
             x, nsc = jax.lax.scan(
                 lambda x, layer: stage_body(x, *layer), x, (sp, sc))
         new_caches.append(nsc)
+    return _serving_logits(params, cfg, x, mesh, rules), new_caches
+
+
+def _serving_logits(params: dict, cfg: ModelConfig, x: Array, mesh,
+                    rules) -> Array:
+    """Final norm + LM head for prefill and decode, emitted in f32.
+
+    The head contracts bf16 operands into f32 and keeps that result: bf16
+    logits put many vocabulary entries on the same value, so greedy argmax
+    would break exact ties by index, and the one-ulp noise of a sharded
+    reduction order would pick a different token."""
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    logits = constrain(logits, ("act_batch", "act_seq", "act_vocab"), mesh, rules)
-    return logits, new_caches
+    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype),
+                        preferred_element_type=jnp.float32)
+    return constrain(logits, ("act_batch", "act_seq", "act_vocab"), mesh,
+                     rules)
 
 
 def prefill(params: dict, cfg: ModelConfig, tokens: Array, cache: list,
@@ -802,12 +814,7 @@ def paged_decode_step(params: dict, cfg: ModelConfig, tokens: Array,
                 lambda x, xs_l: stage_body(x, xs_l[0], xs_l[1], xs_l[2]),
                 x, (sp, dc, jnp.arange(stage.repeat, dtype=jnp.int32)))
         new_delta.append(ns)
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = jnp.einsum("bsd,dv->bsv", x, head.astype(x.dtype))
-    logits = constrain(logits, ("act_batch", "act_seq", "act_vocab"), mesh,
-                       rules)
-    return logits, new_delta
+    return _serving_logits(params, cfg, x, mesh, rules), new_delta
 
 
 def paged_scatter_decode(cfg: ModelConfig, cache: dict, delta: list,

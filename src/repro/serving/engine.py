@@ -24,8 +24,10 @@ extra forward pass: the paper's probe SLM "is" the local SLM.
 With a ``(data, model)`` mesh attached (``launch/mesh.py::serving_mesh``)
 every phase runs SPMD-partitioned: parameters placed by the logical-axis
 rules, caches and batch dims sharded on 'data', jitted entry points built
-with explicit in/out shardings (docs/SHARDING.md).  Greedy tokens are
-bitwise-identical to the single-device path.
+with explicit in/out shardings (docs/SHARDING.md).  A data-parallel mesh is
+bitwise-identical to the single-device path; tensor parallelism reorders
+the 'model'-sharded reductions, so its logits carry about one bf16 ulp and
+greedy tokens agree except at near-ties.
 """
 
 from __future__ import annotations
@@ -554,21 +556,13 @@ class InferenceEngine:
     # this engine triggers write/read XLA executables there — a second
     # process constructing the same engine performs ZERO fresh compiles
     # for already-seen (config, bucket, mesh) cells (serve() cold start).
+    # A set JAX_COMPILATION_CACHE_DIR wins over it (repro.compile_cache).
     compilation_cache_dir: str | None = None
 
     def __post_init__(self):
         if self.compilation_cache_dir is not None:
-            jax.config.update("jax_compilation_cache_dir",
-                              self.compilation_cache_dir)
-            # cache every executable, however small/fast to compile —
-            # serve() cold-start cost is dominated by many small jits
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            # any jit BEFORE this point (param init, another engine) latches
-            # the cache module into "initialized, disabled" — re-arm it so
-            # it picks up the directory we just configured
-            from jax.experimental.compilation_cache import compilation_cache
-            compilation_cache.reset_cache()
+            from repro.compile_cache import enable_compilation_cache
+            enable_compilation_cache(self.compilation_cache_dir)
         self._mesh_jits: dict = {}
         # host-side dispatch accounting: how many cold prefills, warm
         # continuation prefills and decode-only resumes this engine issued

@@ -176,6 +176,7 @@ def test_second_process_compiles_nothing_fresh(tmp_path):
     cache_dir = tmp_path / "xla-cache"
     env = dict(os.environ, PYTHONPATH=os.path.join(
         os.path.dirname(__file__), "..", "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)   # it would win over the arg
 
     def run(tag):
         out = tmp_path / f"toks-{tag}.npy"
@@ -191,6 +192,36 @@ def test_second_process_compiles_nothing_fresh(tmp_path):
     toks2, files2 = run(2)
     assert files2 == files1, (len(files1), len(files2))
     np.testing.assert_array_equal(toks1, toks2)
+
+
+def test_cache_env_var_wins_over_engine_arg(tmp_path):
+    """A set JAX_COMPILATION_CACHE_DIR decides where executables go; the
+    engine's compilation_cache_dir never overrides it."""
+    env_dir, arg_dir = tmp_path / "from-env", tmp_path / "from-arg"
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(env_dir),
+               PYTHONPATH=os.path.join(os.path.dirname(__file__), "..",
+                                       "src"))
+    proc = subprocess.run(
+        [sys.executable, "-c", CACHE_SCRIPT, str(arg_dir),
+         str(tmp_path / "toks.npy")],
+        env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert os.listdir(env_dir)
+    assert not arg_dir.exists() or not os.listdir(arg_dir)
+
+
+def test_cache_dir_resolution(monkeypatch):
+    """Environment first, then the caller's path, then one fixed,
+    git-ignored directory in the checkout."""
+    from repro import compile_cache as cc
+    monkeypatch.setenv(cc.ENV_VAR, "/env/cache")
+    assert cc.cache_dir("/arg/cache") == "/env/cache"
+    monkeypatch.delenv(cc.ENV_VAR)
+    assert cc.cache_dir("/arg/cache") == "/arg/cache"
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.cache_dir() == os.path.join(root, ".jax_cache")
+    with open(os.path.join(root, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 # ---------------------------------------------------------------------------

@@ -325,7 +325,8 @@ class TestBlockSnapping:
                                    rtol=2e-5, atol=2e-5)
 
     def test_uncertainty_non_multiple_vocab(self):
-        # V=672 vs an explicit bv=256 cap: snaps to 224
+        # V=672 vs an explicit bv=256 cap: no multiple of 128 divides 672,
+        # so the whole row is one lane tile
         B, N, V = 2, 8, 672
         logits = jax.random.normal(KEYS[0], (B, N, V), jnp.float32) * 3
         toks = jax.random.randint(KEYS[1], (B, N), 0, V)

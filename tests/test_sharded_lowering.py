@@ -21,8 +21,10 @@ from repro import configs as C
 from repro.models import transformer as T
 from repro.training import optimizer as opt, train as TR
 from repro.distributed import sharding as sh
+from jax.sharding import AxisType
 
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 out = {}
 for arch in %ARCHS%:
     cfg = C.get_smoke(arch)
@@ -46,7 +48,6 @@ for arch in %ARCHS%:
     with mesh:
         compiled = step.lower(abs_p, opt.abstract_state(abs_p), batch).compile()
     ca = compiled.cost_analysis()
-    ca = ca[0] if isinstance(ca, list) else ca    # jax<0.5 returns [dict]
     out[arch] = {"flops": ca.get("flops", 0.0)}
 print("RESULT " + json.dumps(out))
 """

@@ -501,6 +501,16 @@ class TestRepoIsClean:
         fs = run_probes(only={"shard-coverage", "pallas-grid"})
         assert fs == [], "\n".join(f.message for f in fs)
 
+    def test_pallas_grid_flags_untileable_blocks(self, monkeypatch):
+        # the old divisor-only policy picks a 148-row key tile for a
+        # 2368-slot cache: it divides, but the TPU refuses the block
+        from repro.kernels import blocking
+        from tools.swarmlint.probes import run_probes
+        monkeypatch.setattr(blocking, "flash_blocks", lambda S, T: (
+            blocking.snap_block(S, 256), blocking.snap_block(T, 256)))
+        fs = run_probes(only={"pallas-grid"})
+        assert any("T=2368" in f.message for f in fs), fs
+
     @pytest.mark.slow
     def test_abstract_probes_green(self):
         # decode-dtype eval-shapes every arch; donation-alias lowers the

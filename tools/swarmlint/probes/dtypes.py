@@ -38,7 +38,7 @@ def _jaxpr_eqns(jaxpr):
 
 
 def _sub_jaxprs(value):
-    from jax._src.core import ClosedJaxpr, Jaxpr  # stable across 0.4.x
+    from jax._src.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):

@@ -1,11 +1,14 @@
 """``pallas-grid`` — every Pallas kernel's block shapes must divide the
-geometry of every config the engine can serve.
+geometry of every config the engine can serve, and tile it the way the
+TPU's compiler accepts.
 
 The kernels tile with ``grid = dim // block``; a block that does not
 divide its dimension trips the kernel's divisibility assert on TPU at
 the first request with that geometry — long after CI's interpret-mode
-parity tests passed on friendlier shapes.  This probe sweeps, for every
-config in ``configs/`` (full *and* smoke):
+parity tests passed on friendlier shapes.  A block whose sublane size is
+not a multiple of 8 (lane size: 128) and not the whole dim is refused by
+the TPU compiler outright.  This probe sweeps, for every config in
+``configs/`` (full *and* smoke):
 
 * the decode-attention time tile over every legal cache length (the
   engine grows caches in 64-slot granules, lcm'd with attn_kv_block
@@ -44,6 +47,12 @@ def _cache_lengths(kv_block: int, block_len: int) -> List[int]:
     return sorted(set(lengths))
 
 
+def _tiles(dim: int, block: int, align: int) -> bool:
+    """``block`` divides ``dim`` and is a legal TPU block dim: a multiple
+    of ``align`` or the whole ``dim``."""
+    return dim % block == 0 and (block % align == 0 or block == dim)
+
+
 def check() -> List[Finding]:
     from repro import configs as C
     from repro.kernels import blocking
@@ -66,25 +75,31 @@ def check() -> List[Finding]:
         for cfg, is_full in ((C.get_config(arch), True),
                              (C.get_smoke(arch), False)):
             kvb = cfg.attn_kv_block
+            sub, lane = blocking.SUBLANE, blocking.LANE
             for T in _cache_lengths(kvb, block_len):
                 bt = blocking.decode_blocks(T)
-                if T % bt:
+                if not _tiles(T, bt, sub):
                     bad(_BLOCKING_PATH,
-                        f"{arch}: decode tile {bt} does not divide cache "
+                        f"{arch}: decode tile {bt} does not tile cache "
                         f"length {T}")
-                for S in (64, 128, 256, 320, 512, 1024):
+                for S in (8, 64, 128, 256, 320, 512, 1024):
                     bq, bk = blocking.flash_blocks(S, T)
-                    if S % bq or T % bk:
+                    if not (_tiles(S, bq, sub) and _tiles(T, bk, sub)):
                         bad(_BLOCKING_PATH,
                             f"{arch}: flash tiles ({bq}, {bk}) do not "
-                            f"divide (S={S}, T={T})")
+                            f"tile (S={S}, T={T})")
             V = cfg.vocab_size
             for N in _BATCHES:
                 bn, bv = blocking.uncertainty_blocks(N, V)
-                if N % bn or V % bv:
+                if not (_tiles(N, bn, sub) and _tiles(V, bv, lane)):
                     bad(_BLOCKING_PATH,
                         f"{arch}: uncertainty tiles ({bn}, {bv}) do not "
-                        f"divide (N={N}, V={V})")
+                        f"tile (N={N}, V={V})")
+            if block_len % sub:
+                bad(_ENGINE_PATH,
+                    f"pool block_len {block_len} is not a multiple of "
+                    f"{sub}: the decode kernel DMAs whole blocks as its "
+                    "sublane tile")
             # smoke configs pick a matching block_len at construction (the
             # engine validates); the DEFAULT block_len must fit full configs
             if is_full and cfg.window is not None and \
