@@ -14,8 +14,9 @@ One chip:
            bf16 and int8 KV pools.  The compiled prefill and decode
            dispatches must hold the Pallas kernels (``tpu_custom_call``);
            the kernel engine is compared with the plain XLA engine (chunked
-           prefill, gathered decode) and the int8 pool with the bf16 one,
-           greedy tokens tie-aware, as in tests/test_quantized.py.
+           prefill, gathered decode) tie-aware, as in
+           tests/test_prefill_parity.py, and the int8 pool with the bf16 one
+           as in tests/test_quantized.py.
   gateway  one ``Gateway.answer_batch`` over FactWorld study queries:
            probe smollm-135m, swarm of smollm-135m, swarm-edge-1b and
            mamba2-780m, cloud tier llama3-8b cut to 4 layers (the depth the
@@ -24,7 +25,9 @@ Four chips:
   mesh     llama3-8b at full depth, tensor-parallel over a (1, 4) serving
            mesh, serves a few requests; then llama3-8b cut to 8 layers runs
            on one device and on that mesh, greedy tokens compared
-           tie-aware, as in tests/test_prefill_parity.py.
+           tie-aware, as in tests/test_prefill_parity.py, with limits
+           taken from the plain XLA path on the same mesh and a planted
+           fault that the limits must refuse.
 
 The script exits non-zero on any failure, and at once when JAX finds no
 TPU.  Its last line on success is one JSON object naming the device.
@@ -68,37 +71,39 @@ def import_repro():
           f"repro imported from {where}, not from {src}")
 
 
-def tie_aware(name, ref_toks, ref_lg, toks, lg, *, margin, max_tol=None,
-              rms_tol=None):
-    """Greedy streams agree until a flip; on the agreed prefix the logits
-    differ by at most ``max_tol`` anywhere and ``rms_tol`` in RMS (each
-    where given); a flip happens only where the reference's top-2 margin
-    is within ``margin`` (after it the histories differ)."""
-    ref_lg, lg = np.asarray(ref_lg, np.float32), np.asarray(lg, np.float32)
-    check(np.isfinite(lg).all(), f"{name}: non-finite logits")
+def compare(ref, got) -> dict:
+    """Greedy stream ``got`` against ``ref``: tokens agreed before the first
+    flip, the flips as (row, step, reference top-2 margin), and max and RMS
+    |dlogit| over the steps whose history both share (through the flip)."""
+    rt, gt = ref["tokens"], got["tokens"]
+    rl = np.asarray(ref["logits"], np.float32)
+    gl = np.asarray(got["logits"], np.float32)
     agreed, flips, diffs = 0, [], []
-    for b in range(ref_toks.shape[0]):
-        mism = np.nonzero(ref_toks[b] != toks[b])[0]
-        n = int(mism[0]) if len(mism) else ref_toks.shape[1]
+    for b in range(rt.shape[0]):
+        mism = np.nonzero(rt[b] != gt[b])[0]
+        n = int(mism[0]) if len(mism) else rt.shape[1]
         agreed += n
-        diffs.append((ref_lg[b, :n] - lg[b, :n]).ravel())
+        diffs.append((rl[b, :n + 1] - gl[b, :n + 1]).ravel())
         if len(mism):
-            top2 = np.sort(ref_lg[b, n])[-2:]
+            top2 = np.sort(rl[b, n])[-2:]
             flips.append((b, n, float(top2[1] - top2[0])))
     d = np.concatenate(diffs)
-    worst = float(np.abs(d).max()) if d.size else 0.0
-    rms = float(np.sqrt(np.mean(d * d))) if d.size else 0.0
-    log(f"{name}: {agreed}/{ref_toks.size} greedy tokens agree before any "
-        f"flip, flips (row, step, top-2 margin) {flips}, |dlogit| on the "
-        f"prefix max {worst} rms {rms} (limits max {max_tol}, rms {rms_tol}, "
-        f"margin {margin})")
-    check(max_tol is None or worst <= max_tol,
-          f"{name}: logits differ by {worst} > {max_tol} on the agreed "
-          "greedy prefix")
-    check(rms_tol is None or rms <= rms_tol,
-          f"{name}: logits differ by {rms} > {rms_tol} in RMS on the agreed "
-          "greedy prefix")
-    for b, n, gap in flips:
+    return {"agreed": f"{agreed}/{rt.size}", "flips": flips,
+            "max": float(np.abs(d).max()),
+            "rms": float(np.sqrt(np.mean(d * d)))}
+
+
+def tie_aware(name, ref, got, *, max_tol, margin):
+    """tests/test_prefill_parity.py's rule: greedy streams agree until a
+    flip, through which the logits differ by at most ``max_tol``; a flip
+    happens only where the reference's top-2 margin is within ``margin``."""
+    c = compare(ref, got)
+    log(f"{name}: {c} (limits max {max_tol}, margin {margin})")
+    check(np.isfinite(np.asarray(got["logits"], np.float32)).all(),
+          f"{name}: non-finite logits")
+    check(c["max"] <= max_tol, f"{name}: logits differ by {c['max']} > "
+                               f"{max_tol} on the shared history")
+    for b, n, gap in c["flips"]:
         check(gap <= margin, f"{name}: row {b} flips at step {n} with a "
                              f"top-2 margin of {gap} > {margin}")
 
@@ -192,15 +197,16 @@ def phase_serve(seed: int) -> None:
     # the logits here have a spread of about 0.5 and a median top-2 margin
     # of about 1.3: a wrong mask, head or block moves them by that much,
     # a different f32 accumulation order by far less than 0.1
-    tie_aware("bf16 kernels vs XLA oracle", gen["xla"]["tokens"],
-              gen["xla"]["logits"], gen["bf16"]["tokens"],
-              gen["bf16"]["logits"], max_tol=0.1, margin=0.1)
-    # tests/test_quantized.py's rule with its headroom: the budget is ~4x
-    # the measured gap — 0.036 at these widths and this seed, where the
-    # 2-layer smoke config's 0.02 budget does not carry over
-    tie_aware("int8 pool vs bf16 pool", gen["bf16"]["tokens"],
-              gen["bf16"]["logits"], gen["int8"]["tokens"],
-              gen["int8"]["logits"], max_tol=0.15, margin=0.15)
+    tie_aware("bf16 kernels vs XLA oracle", gen["xla"], gen["bf16"],
+              max_tol=0.1, margin=0.1)
+    # tests/test_quantized.py's rule: identical greedy tokens, every logit
+    # within the budget.  The budget keeps that file's ~4x headroom over
+    # the measured gap (0.034 at these widths and this seed), where the
+    # 2-layer smoke config's 0.02 does not carry over
+    c = compare(gen["bf16"], gen["int8"])
+    log(f"int8 pool vs bf16 pool: {c} (budget 0.15, no flips)")
+    check(not c["flips"], "int8 pool: greedy tokens differ from bf16")
+    check(c["max"] <= 0.15, f"int8 pool: logits differ by {c['max']} > 0.15")
     agree = sum(int(np.sum(served[("bf16", "cold")][i]
                            == served[("int8", "cold")][i]))
                 for i in range(len(prompts)))
@@ -308,29 +314,60 @@ def phase_mesh(seed: int) -> None:
     del eng, params, fin
     gc.collect()
 
-    # the same layer-cut model on one device and on the mesh
+    # The same layer-cut model on one device and on the mesh.  Tensor
+    # parallelism sums each layer's partial products in another order, so
+    # the mesh's logits differ from one device's by a noise that grows with
+    # width and depth.  Two more runs on the mesh calibrate the check: the
+    # plain XLA path (chunked prefill, gathered decode; no kernel, no
+    # shard_map) measures that noise alone, and the kernels with a planted
+    # fault (every query group reads the next 'model' shard's KV heads, as
+    # a wrong kernel split would) show what the check must refuse.
     cut = dataclasses.replace(cfg, num_layers=8)
     params = jax.jit(lambda k: T.init_params(cut, k))(key)
     batch = pad_prompts(prompts)
-    single = InferenceEngine("llama3-8b-8l", cut, params, ucfg, paged=True)
-    r0 = single.generate(batch, 12)
-    sharded = InferenceEngine("llama3-8b-8l-tp4", cut, params, ucfg,
-                              paged=True, mesh=mesh)
-    r1 = sharded.generate(batch, 12)
-    # tests/test_prefill_parity.py's rule — prefix logits close, a flip
-    # only inside a small top-2 margin — with its limits scaled to the
-    # logits' spread.  The test's absolute 0.01 belongs to 2-layer smoke
-    # widths: tensor parallelism sums each layer's partial products in
-    # another order and rounds them to bf16, so the gap to one device grows
-    # with width and depth.  On a v5e at these widths single entries of the
-    # 6M compared logits move by up to ~12% of the spread, so the bound is
-    # on the RMS.  A wrong shard, mask or missing reduction moves the
-    # logits by about their own spread (RMS ~1.4x it): 10% lies far below.
-    # Top-2 margins of 128k random logits average ~0.2x the spread.
-    spread = float(np.std(np.asarray(r0["logits"], np.float32)))
-    tie_aware("llama3-8b (8 layers) one device vs (1, 4) mesh",
-              r0["tokens"], r0["logits"], r1["tokens"], r1["logits"],
-              rms_tol=0.1 * spread, margin=0.05 * spread)
+    max_new = 12
+
+    def mesh_engine(name, c, p, **kw):
+        return InferenceEngine(name, c, p, ucfg, paged=True, mesh=mesh, **kw)
+
+    ref = InferenceEngine("llama3-8b-8l", cut, params, ucfg,
+                          paged=True).generate(batch, max_new)
+    kern = mesh_engine("llama3-8b-8l-tp4", cut, params).generate(
+        batch, max_new)
+    xla = mesh_engine(
+        "llama3-8b-8l-tp4-xla",
+        dataclasses.replace(cut, attn_prefill_impl="chunked"), params,
+        attn_decode_impl="gather").generate(batch, max_new)
+    shift = max(cut.num_kv_heads // mesh.shape["model"], 1)
+    bad = jax.tree_util.tree_map_with_path(
+        lambda path, w: (jax.numpy.roll(w, shift, axis=-2)
+                         if path[-1].key in ("wk", "wv") else w), params)
+    fault = mesh_engine("llama3-8b-8l-tp4-fault", cut, bad).generate(
+        batch, max_new)
+
+    spread = float(np.std(np.asarray(ref["logits"], np.float32)))
+    noise = compare(ref, xla)
+    # the kernel path may add at most the XLA path's noise again; a flip
+    # needs the two top logits to move by their margin, at most twice the
+    # largest difference tensor parallelism alone made
+    rms_tol, margin = 2 * noise["rms"], 2 * noise["max"]
+    log(f"llama3-8b (8 layers) one device vs (1, 4) mesh, logit spread "
+        f"{spread}: XLA path (noise witness) {noise}; limits rms {rms_tol} "
+        f"top-2 margin {margin}")
+    got = compare(ref, kern)
+    log(f"  kernels: {got}")
+    bad_got = compare(ref, fault)
+    log(f"  kernels, planted KV-head shard fault: {bad_got}")
+    check(np.isfinite(np.asarray(kern["logits"], np.float32)).all(),
+          "mesh kernels: non-finite logits")
+    check(got["rms"] <= rms_tol,
+          f"mesh kernels: RMS |dlogit| {got['rms']} > {rms_tol}")
+    for b, n, gap in got["flips"]:
+        check(gap <= margin, f"mesh kernels: row {b} flips at step {n} with "
+                             f"a top-2 margin of {gap} > {margin}")
+    check(bad_got["rms"] > rms_tol,
+          f"the planted fault passes the check (RMS {bad_got['rms']} <= "
+          f"{rms_tol}): it cannot tell a wrong shard from noise")
 
 
 def main(argv=None) -> int:

@@ -271,13 +271,22 @@ def constrain(x: jax.Array, logical: Sequence[str | None], mesh: Mesh | None,
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def _data_axes(mesh: Mesh) -> tuple[str, ...]:
+    return tuple(a for a in ("pod", "data") if a in mesh.shape)
+
+
+def data_shards(mesh: Mesh) -> int:
+    """Number of batch shards = product of pod/data axis sizes."""
+    return _axis_size(mesh, _data_axes(mesh))
+
+
 def kernel_split(mesh: Mesh, batch: int, heads: int) -> tuple[Any, Any]:
     """(batch entry, head entry) of the ``shard_map`` specs for a kernel
     that is independent per batch row and per (KV) head: rows split over
     the mesh's pod/data axes and heads over 'model', each only where it
     divides — otherwise that dim is replicated and every shard computes
     all of it."""
-    daxes = tuple(a for a in ("pod", "data") if a in mesh.shape)
+    daxes = _data_axes(mesh)
     b = None
     if daxes and batch % _axis_size(mesh, daxes) == 0:
         b = daxes if len(daxes) > 1 else daxes[0]

@@ -23,7 +23,7 @@ SUBLANE = 8     # second-minor block dim: a multiple of this, or the whole dim
 LANE = 128      # minor block dim: a multiple of this, or the whole dim
 
 
-def snap_block(dim: int, cap: int, align: int = 1) -> int:
+def snap_block(dim: int, cap: int, align: int) -> int:
     """Largest divisor of ``dim`` that is <= ``cap`` and a multiple of
     ``align``; ``dim`` itself when there is none."""
     if dim <= 0:

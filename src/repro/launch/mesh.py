@@ -14,6 +14,8 @@ from __future__ import annotations
 import jax
 from jax.sharding import AxisType
 
+from repro.distributed.sharding import data_shards  # noqa: F401 (re-export)
+
 
 def _mesh(shape, axes):
     """Mesh with Auto axis types: the program places arrays through
@@ -60,11 +62,3 @@ def serving_mesh(model_parallel: int = 1):
         model_parallel -= 1
     return _mesh((n // model_parallel, model_parallel), ("data", "model"))
 
-
-def data_shards(mesh) -> int:
-    """Number of batch shards = product of pod/data axis sizes."""
-    n = 1
-    for a in ("pod", "data"):
-        if a in mesh.shape:
-            n *= mesh.shape[a]
-    return n

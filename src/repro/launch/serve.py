@@ -160,7 +160,9 @@ def main():
     ap.add_argument("--model-parallel", type=int, default=0,
                     help="serve on a (data, model) mesh over the live "
                          "devices with this much tensor parallelism "
-                         "(0 = single-device engines)")
+                         "(0 = single-device engines; on TPU the paged "
+                         "decode kernel needs it to equal the device count, "
+                         "docs/SHARDING.md)")
     ap.add_argument("--paged", action="store_true",
                     help="serve every tier off the paged block-pool cache "
                          "(docs/RUNTIME.md 'Paged caches & prefix sharing')")

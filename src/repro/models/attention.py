@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.sharding import constrain, kernel_split
+from repro.distributed.sharding import constrain, data_shards, kernel_split
 from repro.models import quant as Q
 from repro.models.common import (ModelConfig, ParamDef, apply_rope,
                                  norm_def, normal_init, rmsnorm, rope_angles,
@@ -735,8 +735,17 @@ def attn_decode_paged(p: dict, x: Array, pool: KVCache, table: Array,
         if quantized:
             args += (ksp, vsp)
         if mesh is not None:
-            # a slot's table names pool blocks on every shard, so the pool
-            # block dim is whole on each device; rows and KV heads split
+            # a slot's table names pool blocks on any 'data' shard, so the
+            # kernel needs the whole block dim on each device.  The pool is
+            # split over 'data' (act_pool): with data > 1 every layer's pool
+            # would be all-gathered onto every device each step.
+            if data_shards(mesh) > 1:
+                raise NotImplementedError(
+                    "the paged decode kernel needs block tables that name "
+                    "shard-local pool blocks to run on a mesh with data "
+                    f"axes > 1 ({dict(mesh.shape)}); serve tensor-parallel "
+                    "(serving_mesh(model_parallel=n_devices)) or with "
+                    "attn_decode_impl='gather'")
             b, hd = kernel_split(mesh, B, K)
             pool_spec = P(None, None, hd)
             kern = jax.shard_map(

@@ -294,8 +294,13 @@ class TestBlockSnapping:
         from repro.kernels.blocking import snap_block
         for dim in (64, 192, 320, 640, 1280, 49152, 128256, 152064, 202048):
             for cap in (8, 256, 512, 2048):
-                b = snap_block(dim, cap)
+                b = snap_block(dim, cap, 1)
                 assert 1 <= b <= min(cap, dim) and dim % b == 0, (dim, cap)
+                for align in (8, 128):   # TPU tiles, else the whole dim
+                    b = snap_block(dim, cap, align)
+                    assert dim % b == 0 and (
+                        b == dim or (b % align == 0 and b <= cap)), (
+                            dim, cap, align, b)
         # the documented regressions: old policy was min(cap, dim)
         assert 640 % min(512, 640) != 0
         assert 128256 % min(2048, 128256) != 0

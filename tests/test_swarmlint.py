@@ -507,7 +507,7 @@ class TestRepoIsClean:
         from repro.kernels import blocking
         from tools.swarmlint.probes import run_probes
         monkeypatch.setattr(blocking, "flash_blocks", lambda S, T: (
-            blocking.snap_block(S, 256), blocking.snap_block(T, 256)))
+            blocking.snap_block(S, 256, 1), blocking.snap_block(T, 256, 1)))
         fs = run_probes(only={"pallas-grid"})
         assert any("T=2368" in f.message for f in fs), fs
 

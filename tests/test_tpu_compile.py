@@ -10,21 +10,30 @@ test worker collects the same tests and only the one that runs this file
 loads the TPU library.
 """
 
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from repro import configs as C
+from repro.core.uncertainty import UncertaintyConfig
+from repro.distributed import sharding as sh
 from repro.kernels.decode_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.flash_attention.kernel import (
     flash_attention_positions_pallas)
 from repro.kernels.swarm_uncertainty.kernel import uncertainty_pallas
+from repro.models import transformer as T
+from repro.serving import engine as E
 
 ARCHS = ["smollm-135m", "swarm-edge-1b"]
 BLOCK_LEN = 64          # the engine's default pool block
+POOL_BLOCKS = 96        # blocks per layer pool in the mesh compiles
 
 
 @pytest.fixture(scope="module")
@@ -118,3 +127,61 @@ def test_uncertainty_compiles(one_chip, arch):
     text = _compiled_text(fn, one_chip, ((4, 8, V), jnp.float32),
                           ((4, 8), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def _decode_dispatch(topo, shape, monkeypatch):
+    """Lower the engine's kernel-first paged decode dispatch for llama3-8b
+    (published widths, cut to 2 layers) on a described (data, model) v5e
+    mesh, its operands placed as the engine places them.  The program asks
+    ``jax.default_backend()`` which kernel to run; here that is the CPU, so
+    the test answers for the chip it compiles for."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = Mesh(np.asarray(topo.devices).reshape(shape), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    rules = sh.SERVE_RULES
+    cfg = dataclasses.replace(C.get_config("llama3-8b"), num_layers=2)
+    B, nb, steps = 8, 4, 8
+
+    def placed(tree, axes, act):
+        specs = sh.tree_specs(tree, axes, mesh,
+                              rules.act_rules if act else rules.param_rules)
+        return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)), tree, specs)
+
+    def rep(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt,
+                                    sharding=NamedSharding(mesh, P()))
+
+    params = placed(T.abstract_params(cfg), T.param_axes(cfg), act=False)
+    pool = jax.eval_shape(
+        lambda: T.init_block_pool(cfg, POOL_BLOCKS, BLOCK_LEN, 16))
+    pool = placed(pool, T.paged_cache_axes(cfg)["layers"], act=True)
+    cache = T.paged_cache(pool, rep((B, nb), jnp.int32), rep((B,), jnp.int32))
+    return E._decode_scan_paged.lower(
+        params, cfg, rep((B,), jnp.int32), rep((B, cfg.vocab_size),
+                                               jnp.float32),
+        cache, rep((B,), jnp.int32), rep((2,), jnp.uint32),
+        UncertaintyConfig(mode="distribution"), steps, True,
+        with_logits=False, impl="kernel", mesh=mesh, rules=rules)
+
+
+def test_paged_decode_tensor_parallel_keeps_pool_local(topo, one_chip,
+                                                       monkeypatch):
+    """On a (1, 4) mesh the decode kernel runs per KV-head shard: the
+    compiled dispatch holds the kernel and moves no pool block between
+    devices (an all-gather of the pool would show as a bf16 pool-shaped
+    all-gather)."""
+    text = _decode_dispatch(topo, (1, 4), monkeypatch).compile().as_text()
+    assert "tpu_custom_call" in text
+    pool = re.compile(rf"= bf16\[\d+,{POOL_BLOCKS},{BLOCK_LEN},\d+,128\]\S* "
+                      r"all-gather\(")
+    gathers = [l for l in text.splitlines() if pool.search(l)]
+    assert not gathers, gathers[:2]
+
+
+def test_paged_decode_refuses_data_parallel_mesh(topo, one_chip,
+                                                 monkeypatch):
+    """With data > 1 the kernel would need every layer's whole pool on
+    every device each step; the dispatch refuses to build instead."""
+    with pytest.raises(NotImplementedError, match="shard-local pool blocks"):
+        _decode_dispatch(topo, (2, 2), monkeypatch)
