@@ -27,8 +27,6 @@ The harness is also the enforcement point for the kernel-first claims:
                           gathered-view executable provably carries;
 * ``--assert-ratio X``  — fail unless kernel-first decode-step wall-clock
                           is <= X * gathered-view (CI floor: 1.0);
-* ``--profile DIR``     — wrap one timed pass of each phase in a
-                          ``jax.profiler`` trace for offline inspection;
 * ``--compilation-cache-dir`` — engine-level persistent XLA cache, so a
                           re-run skips every already-seen jit.
 
@@ -158,9 +156,6 @@ def bench_impl(args, impl: str, prompts, span,
     out = {}
     for name, (fn, nbytes, per) in phases.items():
         sec = best_of(fn, args.iters)
-        if args.profile:
-            with jax.profiler.trace(f"{args.profile}/{impl}_{name}"):
-                fn()
         model_sec = nbytes / hbm_bw if hbm_bw else None
         out[name] = {
             "ms": sec / per * 1e3,
@@ -235,8 +230,6 @@ def main(argv=None) -> int:
     ap.add_argument("--block-len", type=int, default=32)
     ap.add_argument("--pool-blocks", type=int, default=512)
     ap.add_argument("--iters", type=int, default=20)
-    ap.add_argument("--profile", default=None, metavar="DIR",
-                    help="write a jax.profiler trace per phase under DIR")
     ap.add_argument("--check-hlo", action="store_true",
                     help="assert the kernel-first decode executable drops "
                          "the slot-linear KV view")

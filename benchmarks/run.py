@@ -8,7 +8,6 @@ cached study results.
 from __future__ import annotations
 
 import os
-import sys
 
 
 def main() -> None:
@@ -24,19 +23,6 @@ def main() -> None:
     force = os.environ.get("BENCH_FORCE") == "1"
     res = tables.cached_study(train_steps=120 if fast else 300, force=force)
     rows += tables.emit_rows(res)
-
-    # --- roofline (from dry-run artifacts, if present) --------------------
-    try:
-        from benchmarks import roofline
-        rl = roofline.analyse()
-        rows += roofline.emit_rows(rl)
-        os.makedirs("experiments", exist_ok=True)
-        with open("experiments/roofline.md", "w") as f:
-            f.write(roofline.markdown_table(rl))
-            f.write("\n\n## Hillclimb variants (baseline v0 vs optimized)\n\n")
-            f.write(roofline.hillclimb_table() + "\n")
-    except Exception as e:  # noqa: BLE001
-        print(f"# roofline skipped: {e}", file=sys.stderr)
 
     print("name,us_per_call,derived")
     for name, us, derived in rows:

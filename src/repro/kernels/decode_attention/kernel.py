@@ -269,6 +269,7 @@ def paged_decode_attention_pallas(q: jax.Array, k_pool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(table, index.astype(jnp.int32), p0.astype(jnp.int32), *operands)
 
 
@@ -303,5 +304,6 @@ def decode_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
         interpret=interpret,
+        name="decode_attention",
     )(index.astype(jnp.int32), q.reshape(B, K, G, D), k.reshape(B, T, K * D),
       v.reshape(B, T, K * D), pos.reshape(B, nt, 1, bt))

@@ -182,6 +182,7 @@ def flash_attention_positions_pallas(q: jax.Array, k: jax.Array,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=_scratch(bq, D),
         interpret=interpret,
+        name="flash_attention_positions",
     )(*_head_major(q, k, v), q_positions.reshape(nq, bq, 1).astype(jnp.int32),
       kv_positions.reshape(nk, 1, bk).astype(jnp.int32))
     return out.transpose(0, 2, 1, 3)
@@ -215,5 +216,6 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
         out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         scratch_shapes=_scratch(bq, D),
         interpret=interpret,
+        name="flash_attention",
     )(*_head_major(q, k, v))
     return out.transpose(0, 2, 1, 3)

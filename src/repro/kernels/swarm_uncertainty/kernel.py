@@ -146,5 +146,6 @@ def uncertainty_pallas(logits: jax.Array, tokens: jax.Array, *, k: int = 10,
             pltpu.VMEM((bn, k), jnp.float32),     # top-k buffer
         ],
         interpret=interpret,
+        name="swarm_uncertainty",
     )(logits, tokens.reshape(B, N, 1).astype(jnp.int32))
     return h[..., 0], v[..., 0], hd[..., 0]

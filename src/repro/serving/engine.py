@@ -50,6 +50,7 @@ from repro.models.common import ModelConfig
 from repro.serving.cache_manager import EvictedSessionError, PagedHandle
 from repro.serving.faults import FaultPlan, PoolExhaustedError
 from repro.serving.scheduler import ContinuousBatcher, Request
+from repro.serving.spans import Spans
 
 Array = jax.Array
 
@@ -91,11 +92,13 @@ def _prefill_into(params, cfg: ModelConfig, prompts, s_orig, cache,
     # are inert in every mixer; real columns keep positions 0..s_orig-1
     positions = jnp.broadcast_to(
         jnp.arange(S, dtype=jnp.int32)[None] - (S - s_orig), (B, S))
-    logits, cache = T.prefill(params, cfg, prompts, cache, positions,
-                              mesh=mesh, rules=rules)
-    last = logits[:, -1].astype(jnp.float32)
-    last = sh.constrain(last, ("act_batch", "act_vocab"), mesh, rules)
-    cur = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    with jax.named_scope("prefill.model"):
+        logits, cache = T.prefill(params, cfg, prompts, cache, positions,
+                                  mesh=mesh, rules=rules)
+        last = logits[:, -1].astype(jnp.float32)
+        last = sh.constrain(last, ("act_batch", "act_vocab"), mesh, rules)
+    with jax.named_scope("prefill.sample"):
+        cur = jnp.argmax(last, axis=-1).astype(jnp.int32)
     return cur, last, cache
 
 
@@ -131,16 +134,20 @@ def _decode_scan(params, cfg: ModelConfig, cur, last, cache, pos, rng,
     def body(carry, _):
         cur, last, cache, pos, rng = carry
         # Eq. 2-3 terms of the *emitted* token: cur was chosen from last
-        h, v = U.uncertainty_terms(last[:, None, :], cur[:, None], ucfg)
-        rng, sub = jax.random.split(rng)
-        logits, cache = T.decode_step(params, cfg, cur[:, None], cache, pos,
-                                      mesh=mesh, rules=rules)
-        lg = logits[:, -1].astype(jnp.float32)
-        lg = sh.constrain(lg, ("act_batch", "act_vocab"), mesh, rules)
-        if greedy:
-            nxt = jnp.argmax(lg, axis=-1)
-        else:
-            nxt = jax.random.categorical(sub, lg, axis=-1)
+        with jax.named_scope("decode.uncertainty"):
+            h, v = U.uncertainty_terms(last[:, None, :], cur[:, None], ucfg)
+        with jax.named_scope("decode.sample"):
+            rng, sub = jax.random.split(rng)
+        with jax.named_scope("decode.model"):
+            logits, cache = T.decode_step(params, cfg, cur[:, None], cache,
+                                          pos, mesh=mesh, rules=rules)
+            lg = logits[:, -1].astype(jnp.float32)
+            lg = sh.constrain(lg, ("act_batch", "act_vocab"), mesh, rules)
+        with jax.named_scope("decode.sample"):
+            if greedy:
+                nxt = jnp.argmax(lg, axis=-1)
+            else:
+                nxt = jax.random.categorical(sub, lg, axis=-1)
         out = (cur, h[:, 0], v[:, 0]) + ((last,) if with_logits else ())
         return (nxt.astype(jnp.int32), lg, cache, pos + 1, rng), out
 
@@ -199,12 +206,14 @@ def _prefill_into_paged(params, cfg: ModelConfig, prompts, s_orig, cache,
     [0, s_orig) back.  Returns (cur, last, updated paged cache)."""
     B = prompts.shape[0]
     cache = T.constrain_cache(cache, cfg, mesh, rules)
-    lin = T.paged_gather(cfg, cache)
+    with jax.named_scope("prefill.cache_gather"):
+        lin = T.paged_gather(cfg, cache)
     cur, last, lin = _prefill_into(params, cfg, prompts, s_orig, lin,
                                    mesh=mesh, rules=rules)
-    layers = T.paged_scatter_back(
-        cfg, cache, lin, jnp.zeros((B,), jnp.int32),
-        jnp.broadcast_to(s_orig, (B,)).astype(jnp.int32))
+    with jax.named_scope("prefill.cache_write"):
+        layers = T.paged_scatter_back(
+            cfg, cache, lin, jnp.zeros((B,), jnp.int32),
+            jnp.broadcast_to(s_orig, (B,)).astype(jnp.int32))
     return cur, last, T.paged_cache(layers, cache["table"], cache["rows"])
 
 
@@ -215,10 +224,13 @@ def _prefill_continue_paged(params, cfg: ModelConfig, prompts, s_orig, start,
     """Paged continuation prefill: gather -> ``_prefill_continue`` ->
     scatter blocks [start, start + s_orig) back."""
     cache = T.constrain_cache(cache, cfg, mesh, rules)
-    lin = T.paged_gather(cfg, cache)
+    with jax.named_scope("prefill.cache_gather"):
+        lin = T.paged_gather(cfg, cache)
     cur, last, lin = _prefill_continue(params, cfg, prompts, s_orig, start,
                                        lin, mesh=mesh, rules=rules)
-    layers = T.paged_scatter_back(cfg, cache, lin, start, start + s_orig)
+    with jax.named_scope("prefill.cache_write"):
+        layers = T.paged_scatter_back(cfg, cache, lin, start,
+                                      start + s_orig)
     return cur, last, T.paged_cache(layers, cache["table"], cache["rows"])
 
 
@@ -236,28 +248,34 @@ def _decode_scan_kernel(params, cfg: ModelConfig, cur, last, cache, pos, rng,
     AND logits are bitwise-identical to the gathered-view path."""
     cache = T.constrain_cache(cache, cfg, mesh, rules)
     p0 = pos
-    delta0 = T.paged_decode_carry(cfg, cache, steps)
+    with jax.named_scope("decode.cache_write"):
+        delta0 = T.paged_decode_carry(cfg, cache, steps)
 
     def body(carry, t):
         cur, last, delta, pos_c, rng = carry
-        h, v = U.uncertainty_terms(last[:, None, :], cur[:, None], ucfg)
-        rng, sub = jax.random.split(rng)
-        logits, delta = T.paged_decode_step(
-            params, cfg, cur[:, None], cache, delta, pos_c, t, p0,
-            mesh=mesh, rules=rules)
-        lg = logits[:, -1].astype(jnp.float32)
-        lg = sh.constrain(lg, ("act_batch", "act_vocab"), mesh, rules)
-        if greedy:
-            nxt = jnp.argmax(lg, axis=-1)
-        else:
-            nxt = jax.random.categorical(sub, lg, axis=-1)
+        with jax.named_scope("decode.uncertainty"):
+            h, v = U.uncertainty_terms(last[:, None, :], cur[:, None], ucfg)
+        with jax.named_scope("decode.sample"):
+            rng, sub = jax.random.split(rng)
+        with jax.named_scope("decode.model"):
+            logits, delta = T.paged_decode_step(
+                params, cfg, cur[:, None], cache, delta, pos_c, t, p0,
+                mesh=mesh, rules=rules)
+            lg = logits[:, -1].astype(jnp.float32)
+            lg = sh.constrain(lg, ("act_batch", "act_vocab"), mesh, rules)
+        with jax.named_scope("decode.sample"):
+            if greedy:
+                nxt = jnp.argmax(lg, axis=-1)
+            else:
+                nxt = jax.random.categorical(sub, lg, axis=-1)
         out = (cur, h[:, 0], v[:, 0]) + ((last,) if with_logits else ())
         return (nxt.astype(jnp.int32), lg, delta, pos_c + 1, rng), out
 
     carry, outs = jax.lax.scan(body, (cur, last, delta0, pos, rng),
                                jnp.arange(steps))
     cur2, last2, delta2, pos2, rng2 = carry
-    layers = T.paged_scatter_decode(cfg, cache, delta2, p0)
+    with jax.named_scope("decode.cache_write"):
+        layers = T.paged_scatter_decode(cfg, cache, delta2, p0)
     out_cache = T.paged_cache(layers, cache["table"], cache["rows"])
     toks, h_per, v_per = (o.swapaxes(0, 1) for o in outs[:3])
     lgs = outs[3].swapaxes(0, 1) if with_logits else None
@@ -283,12 +301,14 @@ def _decode_scan_paged(params, cfg: ModelConfig, cur, last, cache, pos, rng,
                                    with_logits=with_logits, mesh=mesh,
                                    rules=rules)
     cache = T.constrain_cache(cache, cfg, mesh, rules)
-    lin = T.paged_gather(cfg, cache)
+    with jax.named_scope("decode.cache_gather"):
+        lin = T.paged_gather(cfg, cache)
     toks, lgs, h_per, v_per, carry = _decode_scan(
         params, cfg, cur, last, lin, pos, rng, ucfg, steps, greedy,
         with_logits=with_logits, mesh=mesh, rules=rules)
     cur2, last2, lin2, pos2, rng2 = carry
-    layers = T.paged_scatter_back(cfg, cache, lin2, pos, pos + steps)
+    with jax.named_scope("decode.cache_write"):
+        layers = T.paged_scatter_back(cfg, cache, lin2, pos, pos + steps)
     out_cache = T.paged_cache(layers, cache["table"], cache["rows"])
     return toks, lgs, h_per, v_per, (cur2, last2, out_cache, pos2, rng2)
 
@@ -392,12 +412,14 @@ def _prefill_continue(params, cfg: ModelConfig, prompts, s_orig, start,
     # padding keeps negative positions => inert in every mixer
     positions = jnp.where(col < s_orig, start[:, None] + col, col - S)
     cache = T.constrain_cache(cache, cfg, mesh, rules)
-    logits, cache = T.prefill(params, cfg, prompts, cache, positions,
-                              continuation=True, mesh=mesh, rules=rules)
-    last = jax.lax.dynamic_slice_in_dim(logits, s_orig - 1, 1, axis=1)
-    last = last[:, 0].astype(jnp.float32)
-    last = sh.constrain(last, ("act_batch", "act_vocab"), mesh, rules)
-    cur = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    with jax.named_scope("prefill.model"):
+        logits, cache = T.prefill(params, cfg, prompts, cache, positions,
+                                  continuation=True, mesh=mesh, rules=rules)
+        last = jax.lax.dynamic_slice_in_dim(logits, s_orig - 1, 1, axis=1)
+        last = last[:, 0].astype(jnp.float32)
+        last = sh.constrain(last, ("act_batch", "act_vocab"), mesh, rules)
+    with jax.named_scope("prefill.sample"):
+        cur = jnp.argmax(last, axis=-1).astype(jnp.int32)
     return cur, last, cache
 
 
@@ -577,6 +599,10 @@ class InferenceEngine:
                          # re-prefills after a warm handle was evicted
                          "famine_deferred": 0, "shed": 0, "expired": 0,
                          "requeued": 0, "reprefill_cold": 0}
+        # serve()'s phases per loop turn (serving/spans.py): serve.turn and
+        # its children serve.admit, serve.prefill (one per admission),
+        # serve.decode, serve.sync, serve.record and serve.retire
+        self.spans = Spans()
         # warm continuation attends CHUNKED over the cache, which needs the
         # cache length divisible by the KV block once it exceeds one block
         # (cold prefill/decode never hit this: they chunk only the span)
@@ -1466,6 +1492,14 @@ class InferenceEngine:
           has passed retire ``shed=True`` — queued ones before taking a
           slot, active ones mid-decode with what they have.  ``None``
           (default) keeps deadlines as pure admission ordering.
+
+        Every loop turn is recorded in ``self.spans`` (and, when a profiler
+        runs, in its trace): ``serve.turn`` (counts: turn, admitted,
+        active, steps, blocks_in_use) around ``serve.admit`` (queued), one
+        ``serve.prefill`` per admission (rid, prompt, bucket, warm),
+        ``serve.decode`` (steps), ``serve.sync`` (the read-back of the
+        chunk's tokens and uncertainty terms), ``serve.record`` (tokens)
+        and ``serve.retire`` (retired rids).
         """
         if (requests is None) == (batcher is None):
             raise ValueError("pass exactly one of requests / batcher")
@@ -1566,7 +1600,8 @@ class InferenceEngine:
 
         now_ms = 0.0
 
-        while not batcher.idle:
+        for turn in self.spans.turns(lambda: not batcher.idle):
+            turn.phase("serve.admit", queued=len(batcher.queue))
             if (faults is not None and batcher.queue
                     and faults.consume("pool") is not None):
                 # injected famine: this admission round sees zero free
@@ -1609,8 +1644,11 @@ class InferenceEngine:
                             f"{self.pool.live_sessions} sessions and no "
                             "slot can admit — grow pool_blocks, release "
                             "sessions, or pass session_ttl_s")
+            turn.counts["admitted"] = len(admitted)
             for i in admitted:
                 req = batcher.slots[i]
+                pc = turn.phase("serve.prefill", rid=req.rid, warm=False,
+                                prompt=0, bucket=0)
                 st = req.state
                 if (st is not None and faults is not None
                         and isinstance(st.cache, PagedHandle)
@@ -1637,6 +1675,7 @@ class InferenceEngine:
                             self.counters["shed"] += 1
                             continue
                         self.counters["reprefill_cold"] += 1
+                pc["warm"] = st is not None
                 if paged:
                     if st is not None:
                         # warm admission: the slot's table row shares the
@@ -1661,6 +1700,7 @@ class InferenceEngine:
                     if req.prompt:
                         p = np.asarray(req.prompt, np.int32)[None]
                         pb, s_orig = self._bucket_right(p)
+                        pc["prompt"], pc["bucket"] = s_orig, pb.shape[1]
                         self.counters["prefill_continue"] += 1
                         fn = (_prefill_continue_paged if paged
                               else _prefill_continue)
@@ -1676,6 +1716,7 @@ class InferenceEngine:
                 else:
                     p = np.asarray(req.prompt, np.int32)[None]
                     pb, s_orig = self._bucket(p)
+                    pc["prompt"], pc["bucket"] = s_orig, pb.shape[1]
                     self.counters["prefill"] += 1
                     if paged:
                         c1, l1, k1 = _prefill_into_paged(
@@ -1708,9 +1749,11 @@ class InferenceEngine:
             # once per engine and only for sizes < decode_chunk that
             # return_state requests actually hit near retirement (bounded
             # by decode_chunk, not by the request mix).
+            dc = turn.phase("serve.decode")
             chunk = min([int(decode_chunk)] +
                         [r.max_new - len(r.generated)
                          for _, r in batcher.active() if r.return_state])
+            dc["steps"] = turn.counts["steps"] = chunk
             if paged:
                 cache = self._paged_dev_cache(slot_tables, slot_rows)
                 toks, _, h_per, v_per, carry = _decode_scan_paged(
@@ -1729,10 +1772,15 @@ class InferenceEngine:
             cur, last, cache, pos, rng = carry
             if paged:
                 self.pool.commit(cache["layers"])
+            turn.phase("serve.sync")
             toks_np = np.asarray(toks)
             h_np, v_np = np.asarray(h_per), np.asarray(v_per)
 
+            rc = turn.phase("serve.record", tokens=0)
             slot_of = {r.rid: i for i, r in batcher.active()}
+            turn.counts["active"] = len(slot_of)
+            if paged:
+                turn.counts["blocks_in_use"] = self.pool.blocks_in_use
             retired_at: dict[int, int] = {}
             for t in range(chunk):
                 active = batcher.active()
@@ -1744,9 +1792,11 @@ class InferenceEngine:
                     a[1] += float(v_np[i, t])
                     a[2] += 1
                 batcher.record_tokens(toks_np[:, t], stop_token)
+                rc["tokens"] += len(active)
                 for i, req in active:
                     if req.done:
                         retired_at.setdefault(req.rid, t)
+            rt = turn.phase("serve.retire")
             for req in batcher.finished:        # retired this chunk
                 i = slot_of.get(req.rid)
                 if i is None:
@@ -1822,6 +1872,7 @@ class InferenceEngine:
                     acc.pop(req.rid, None)
                     batcher.requeue(i)
                     self.counters["requeued"] += 1
+            rt["retired"] = tuple(r.rid for r in batcher.finished)
             drain()
         drain()
         return results
