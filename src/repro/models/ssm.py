@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
+from repro.kernels.ssd_decode import ref as ssd_ref
+from repro.kernels.ssd_decode.ops import ssd_state_update
 from repro.models.common import (ModelConfig, ParamDef, norm_def, normal_init,
                                  ones_init, rmsnorm, zeros_init)
 
@@ -273,37 +275,66 @@ def init_ssm_state(cfg: ModelConfig, batch: int) -> SSMState:
     )
 
 
-def ssd_decode(p: dict, x: Array, state: SSMState, cfg: ModelConfig,
-               mesh=None, rules=None) -> tuple[Array, SSMState]:
-    """One-token decode. x (B,1,D).  On-mesh the carried (B,H,P,N) state is
-    pinned ``(act_batch, act_heads)``-sharded across the decode scan."""
+def _decode_inputs(p: dict, x: Array, conv: Array, cfg: ModelConfig):
+    """A decode step up to the state update: norm, in_proj and the conv
+    step.  Returns (z, conv tail, the f32 operands of the state update:
+    dA, dt (B,H), x (B,H,P), B, C (B,G,N), D (H,))."""
     d_inner, H, P, G, N, conv_dim, _ = _dims(cfg)
     B = x.shape[0]
     h = rmsnorm(x, p["norm"], cfg.norm_eps)
     zxbcdt = h @ p["in_proj"].astype(h.dtype)
     z, xBC, dt = _split_proj(zxbcdt, cfg)
-    xBC, conv_tail = _causal_conv_step(xBC, p["conv_w"], p["conv_b"],
-                                       state.conv)
+    xBC, conv_tail = _causal_conv_step(xBC, p["conv_w"], p["conv_b"], conv)
     xs = xBC[:, 0, :d_inner]
     Bm = xBC[:, 0, d_inner:d_inner + G * N].reshape(B, G, N)
     Cm = xBC[:, 0, d_inner + G * N:].reshape(B, G, N)
     dt = jax.nn.softplus(dt[:, 0].astype(jnp.float32) + p["dt_bias"])  # (B,H)
     A = -jnp.exp(p["A_log"])
     dA = jnp.exp(dt * A[None, :])                                      # (B,H)
-    xh = (xs.reshape(B, H, P).astype(jnp.float32) * dt[..., None])
-    rep = H // G
-    Bh = jnp.repeat(Bm.astype(jnp.float32), rep, axis=1)               # (B,H,N)
-    Ch = jnp.repeat(Cm.astype(jnp.float32), rep, axis=1)
-    s_new = state.ssd * dA[..., None, None] + xh[..., None] * Bh[:, :, None, :]
-    y = jnp.einsum("bhpn,bhn->bhp", s_new, Ch)
-    y = y + xs.reshape(B, H, P).astype(jnp.float32) * p["D_skip"][:, None]
-    y = y.reshape(B, 1, d_inner)
+    step = (dA, dt, xs.reshape(B, H, P).astype(jnp.float32),
+            Bm.astype(jnp.float32), Cm.astype(jnp.float32), p["D_skip"])
+    return z, conv_tail, step
+
+
+def _decode_output(p: dict, x: Array, y: Array, z: Array,
+                   cfg: ModelConfig) -> Array:
+    """A decode step after the state update: gate, norm, out_proj and the
+    residual.  y (B,H,P) f32."""
+    y = y.reshape(x.shape[0], 1, cfg.ssm_d_inner)
     y = rmsnorm((y * jax.nn.silu(z.astype(jnp.float32))).astype(x.dtype),
                 p["gnorm"], cfg.norm_eps)
-    out = x + y @ p["out_proj"].astype(x.dtype)
+    return x + y @ p["out_proj"].astype(x.dtype)
+
+
+def ssd_decode(p: dict, x: Array, state: SSMState, cfg: ModelConfig,
+               mesh=None, rules=None) -> tuple[Array, SSMState]:
+    """One-token decode. x (B,1,D).  On-mesh the carried (B,H,P,N) state is
+    pinned ``(act_batch, act_heads)``-sharded across the decode scan."""
+    z, conv_tail, step = _decode_inputs(p, x, state.conv, cfg)
+    s_new, y = ssd_ref.ssd_step(state.ssd, *step)
+    out = _decode_output(p, x, y, z, cfg)
     state = SSMState(
         ssd=constrain(s_new, ("act_batch", "act_heads", None, None),
                       mesh, rules),
         conv=constrain(conv_tail, ("act_batch", None, "act_ssm_inner"),
                        mesh, rules))
     return out, state
+
+
+def ssd_decode_pooled(p: dict, x: Array, state: SSMState, rows: Array,
+                      layer, cfg: ModelConfig) -> tuple[Array, SSMState]:
+    """One-token decode whose SSD state stays in the pool.  ``state.ssd``
+    is the pool leaf itself — (L, R, H, P, N) with ``layer`` the stage
+    scan's layer index, or (R, H, P, N) with ``layer`` None — and
+    ``state.conv`` the slots' (B, W-1, C) conv-tail rows.  The slots'
+    state rows ``rows`` (B,) are stepped where they lie
+    (``kernels/ssd_decode``; sentinel ids >= R write nothing); returns the
+    block output and SSMState(updated pool, new conv tail)."""
+    z, conv_tail, step = _decode_inputs(p, x, state.conv, cfg)
+    pool = state.ssd if layer is not None else state.ssd[None]
+    with jax.named_scope("decode.ssd_state"):
+        y, pool = ssd_state_update(pool, rows, 0 if layer is None else layer,
+                                   *step)
+    pool = pool if layer is not None else pool[0]
+    return _decode_output(p, x, y, z, cfg), SSMState(ssd=pool,
+                                                     conv=conv_tail)

@@ -198,10 +198,14 @@ def loss_fn(params: dict, cfg: ModelConfig, batch: dict, *,
 # ---------------------------------------------------------------------------
 
 class LayerCache(NamedTuple):
-    """Per-(stage, slot) cache. Exactly one field is used per mixer kind."""
+    """Per-(stage, slot) cache. Exactly one field is used per mixer kind,
+    except in the kernel-first decode carry, where an SSD layer's ``ssd``
+    holds only the slots' conv tails and ``ssd_pool`` its whole state pool
+    (``paged_decode_carry``)."""
     kv: Any = None
     rg: Any = None
     ssd: Any = None
+    ssd_pool: Any = None
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> list:
@@ -707,14 +711,30 @@ def copy_rows(cfg: ModelConfig, layers: list, src: Array, dst: Array) -> list:
 # Kernel-first paged decode: attention reads pool blocks in place
 # ---------------------------------------------------------------------------
 
-def paged_decode_carry(cfg: ModelConfig, cache: dict, steps: int) -> list:
+def ssd_pools_carried(cfg: ModelConfig, mesh=None) -> bool:
+    """Whether the kernel-first decode carries each SSD layer's state pool
+    whole and steps the slots' rows where they lie (``ssm.
+    ssd_decode_pooled``): for configs with SSD layers, off-mesh and on a
+    one-device mesh.  On a mesh of several devices the pool's rows split
+    over 'data' and its heads over 'model', so the dispatch gathers the
+    slots' state rows as the other recurrent layers do."""
+    return (any(m == "ssd" for m, _ in cfg.layer_plan())
+            and (mesh is None or mesh.size == 1))
+
+
+def paged_decode_carry(cfg: ModelConfig, cache: dict, steps: int,
+                       mesh=None) -> list:
     """Initial carry for the kernel-first decode scan: per attention layer an
     O(B * steps) delta write buffer (``attention.init_decode_delta``), per
     recurrent layer the O(B) gathered state rows.  Unlike the gathered-view
     path there is NO cache-length state in the carry — the KV pool itself is
-    a closed-over scan constant that ``paged_decode_step`` reads in place."""
+    a closed-over scan constant that ``paged_decode_step`` reads in place.
+    Where ``ssd_pools_carried``, an SSD layer gathers only its conv-tail
+    rows and carries its state pool itself (``LayerCache.ssd_pool``), which
+    the scan updates in place and the dispatch returns as the new pool."""
     layers, rows = cache["layers"], cache["rows"]
     B = cache["table"].shape[0]
+    pooled = ssd_pools_carried(cfg, mesh)
     out = []
     for stage, sc in zip(cfg.stage_plan(), layers):
         ns = {}
@@ -729,23 +749,32 @@ def paged_decode_carry(cfg: ModelConfig, cache: dict, steps: int) -> list:
                 c = LayerCache(kv=d)
             else:
                 axis = 1 if stacked else 0
-                c = jax.tree.map(
-                    lambda a: jnp.take(a, rows, axis=axis, mode="clip"), c)
+
+                def take(a, axis=axis):
+                    return jnp.take(a, rows, axis=axis, mode="clip")
+                if pooled and c.ssd is not None:
+                    c = LayerCache(ssd=c.ssd._replace(ssd=None,
+                                                      conv=take(c.ssd.conv)),
+                                   ssd_pool=c.ssd.ssd)
+                else:
+                    c = jax.tree.map(take, c)
             ns[f"b{i}"] = c
         out.append(ns)
     return out
 
 
 def _paged_block_step(bp: dict, x: Array, pool_c: LayerCache,
-                      delta_c: LayerCache, table: Array, index: Array,
-                      t: Array, p0: Array, cfg: ModelConfig,
+                      delta_c: LayerCache, table: Array, rows: Array,
+                      index: Array, t: Array, p0: Array, cfg: ModelConfig,
                       kind: tuple[str, str], mesh, rules, layer=None
                       ) -> tuple[Array, LayerCache]:
     """One kernel-first decode block: attention attends through the block
     table in place (pool never copied), recurrent mixers run the unchanged
-    monolithic decode on their carried state rows.  In a stacked stage
-    ``pool_c`` holds the whole repeat-stacked pool and ``layer`` the stage
-    scan's layer index — attention folds it into its block gathers, so the
+    monolithic decode on their carried state rows, and an SSD layer whose
+    carry holds its state pool steps the slots' rows in it.  In a stacked
+    stage ``pool_c`` and ``delta_c.ssd_pool`` hold the whole repeat-stacked
+    pools and ``layer`` the stage scan's layer index — attention folds it
+    into its block gathers and the SSD update into its row address, so the
     stage scan never slices (copies) a per-layer pool."""
     mixer, f = kind
     if mixer in ("attn", "attn_local"):
@@ -760,6 +789,11 @@ def _paged_block_step(bp: dict, x: Array, pool_c: LayerCache,
         x, rg = rglru.rglru_decode(bp["mixer"], x, delta_c.rg, cfg,
                                    mesh=mesh, rules=rules)
         delta_c = delta_c._replace(rg=rg)
+    elif mixer == "ssd" and delta_c.ssd_pool is not None:
+        x, s = ssm.ssd_decode_pooled(
+            bp["mixer"], x, delta_c.ssd._replace(ssd=delta_c.ssd_pool),
+            rows, layer, cfg)
+        delta_c = LayerCache(ssd=s._replace(ssd=None), ssd_pool=s.ssd)
     elif mixer == "ssd":
         x, s = ssm.ssd_decode(bp["mixer"], x, delta_c.ssd, cfg,
                               mesh=mesh, rules=rules)
@@ -792,8 +826,11 @@ def paged_decode_step(params: dict, cfg: ModelConfig, tokens: Array,
     whole pool every decode step, so the stacked pool stays closed over and
     attention folds the layer index into its block gathers
     (``attn_decode_paged(layer=...)``) — the outer decode scan carries no
-    O(pool) state and the stage scan moves none."""
-    layers, table = cache["layers"], cache["table"]
+    O(pool) state and the stage scan moves none.  The one exception is the
+    SSD state pools of ``paged_decode_carry``'s ``LayerCache.ssd_pool``: the
+    stage scan carries them whole (never as xs/ys, which would stack a
+    copy) and each layer updates its slots' rows in them in place."""
+    layers, table, rows = cache["layers"], cache["table"], cache["rows"]
     x = jnp.take(params["embed"], tokens, axis=0).astype(cfg.comp_dtype)
     x = constrain(x, ("act_batch", "act_seq", "act_embed"), mesh, rules)
     new_delta = []
@@ -803,18 +840,40 @@ def paged_decode_step(params: dict, cfg: ModelConfig, tokens: Array,
             nds = {}
             for i, kind in enumerate(stage.blocks):
                 x, nds[f"b{i}"] = _paged_block_step(
-                    lp[f"b{i}"], x, sc[f"b{i}"], d_c[f"b{i}"], table,
+                    lp[f"b{i}"], x, sc[f"b{i}"], d_c[f"b{i}"], table, rows,
                     index, t, p0, cfg, kind, mesh, rules, layer=li)
             return x, nds
 
         if stage.repeat == 1:
             x, ns = stage_body(x, sp, dc, None)
         else:
-            x, ns = jax.lax.scan(
-                lambda x, xs_l: stage_body(x, xs_l[0], xs_l[1], xs_l[2]),
-                x, (sp, dc, jnp.arange(stage.repeat, dtype=jnp.int32)))
+            def scan_body(carry, xs_l, stage_body=stage_body):
+                x, pools = carry
+                x, nds = stage_body(x, xs_l[0], _with_pools(xs_l[1], pools),
+                                    xs_l[2])
+                return (x, _pools(nds)), _with_pools(nds, {})
+
+            (x, pools), ns = jax.lax.scan(
+                scan_body, (x, _pools(dc)),
+                (sp, _with_pools(dc, {}),
+                 jnp.arange(stage.repeat, dtype=jnp.int32)))
+            ns = _with_pools(ns, pools)
         new_delta.append(ns)
     return _serving_logits(params, cfg, x, mesh, rules), new_delta
+
+
+def _pools(dc: dict) -> dict:
+    """The whole-pool leaves (``LayerCache.ssd_pool``) of a stage's decode
+    carry, by block name."""
+    return {name: c.ssd_pool for name, c in dc.items()
+            if c.ssd_pool is not None}
+
+
+def _with_pools(dc: dict, pools: dict) -> dict:
+    """A stage's decode carry with its whole-pool leaves taken from
+    ``pools`` (by block name); ``{}`` clears them."""
+    return {name: c._replace(ssd_pool=pools.get(name))
+            for name, c in dc.items()}
 
 
 def paged_scatter_decode(cfg: ModelConfig, cache: dict, delta: list,
@@ -822,9 +881,10 @@ def paged_scatter_decode(cfg: ModelConfig, cache: dict, delta: list,
     """End-of-dispatch writeback for the kernel-first decode: scatter each
     attention layer's delta rows into their pool slots through the table
     (``attention.paged_scatter_delta`` — O(steps) writes per row) and each
-    recurrent layer's carried state rows.  Produces pools elementwise-equal
-    to the gathered path's ``paged_scatter_back``; sentinel table entries /
-    row ids drop."""
+    recurrent layer's carried state rows; an SSD state pool the scan carried
+    whole (``LayerCache.ssd_pool``) is already updated and becomes the pool.
+    Produces pools elementwise-equal to the gathered path's
+    ``paged_scatter_back``; sentinel table entries / row ids drop."""
     layers, table, rows = cache["layers"], cache["table"], cache["rows"]
     nb = table.shape[1]
     out = []
@@ -849,7 +909,11 @@ def paged_scatter_decode(cfg: ModelConfig, cache: dict, delta: list,
                     idx = (slice(None), rows) if axis else rows
                     return pool_leaf.at[idx].set(
                         d_leaf.astype(pool_leaf.dtype), mode="drop")
-                c = jax.tree.map(one, c, d)
+                if d.ssd_pool is not None:
+                    c = LayerCache(ssd=c.ssd._replace(
+                        ssd=d.ssd_pool, conv=one(c.ssd.conv, d.ssd.conv)))
+                else:
+                    c = jax.tree.map(one, c, d)
             ns[f"b{i}"] = c
         out.append(ns)
     return out

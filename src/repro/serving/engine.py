@@ -249,7 +249,7 @@ def _decode_scan_kernel(params, cfg: ModelConfig, cur, last, cache, pos, rng,
     cache = T.constrain_cache(cache, cfg, mesh, rules)
     p0 = pos
     with jax.named_scope("decode.cache_write"):
-        delta0 = T.paged_decode_carry(cfg, cache, steps)
+        delta0 = T.paged_decode_carry(cfg, cache, steps, mesh=mesh)
 
     def body(carry, t):
         cur, last, delta, pos_c, rng = carry
@@ -598,7 +598,11 @@ class InferenceEngine:
                          # slot-failure requeues, and transparent cold
                          # re-prefills after a warm handle was evicted
                          "famine_deferred": 0, "shed": 0, "expired": 0,
-                         "requeued": 0, "reprefill_cold": 0}
+                         "requeued": 0, "reprefill_cold": 0,
+                         # kernel-first decode dispatches that stepped the
+                         # SSD state rows in place in the pool
+                         # (transformer.ssd_pools_carried)
+                         "ssd_state_inplace": 0}
         # serve()'s phases per loop turn (serving/spans.py): serve.turn and
         # its children serve.admit, serve.prefill (one per admission),
         # serve.decode, serve.sync, serve.record and serve.retire
@@ -650,6 +654,9 @@ class InferenceEngine:
             self.pool = CachePool(self.cfg, L, n_blocks, n_rows,
                                   cache_quant=self.cache_quant,
                                   mesh=self.mesh, rules=self.rules)
+        self._ssd_inplace = int(self.paged
+                                and self.attn_decode_impl == "kernel"
+                                and T.ssd_pools_carried(self.cfg, self.mesh))
         if self.mesh is None:
             return
         self.rules = self.rules or sh.SERVE_RULES
@@ -1069,6 +1076,7 @@ class InferenceEngine:
             max_len = self._cache_len(pb.shape[1], max_new)
             self.counters["prefill"] += 1
             if self.paged:
+                self.counters["ssd_state_inplace"] += self._ssd_inplace
                 handle = self.pool.alloc(B, max_len // self.block_len)
                 out = _generate_fused_paged(
                     self.params, self.cfg, jnp.asarray(pb),
@@ -1101,6 +1109,7 @@ class InferenceEngine:
                 cache, max_len = self._grown_cache(state, need)
             self.counters["prefill_continue"] += 1
             if self.paged:
+                self.counters["ssd_state_inplace"] += self._ssd_inplace
                 out = _generate_continue_paged(
                     self.params, self.cfg, jnp.asarray(pb),
                     jnp.int32(s_orig), state.pos, cache, rng, self.ucfg,
@@ -1251,6 +1260,7 @@ class InferenceEngine:
             rng = state.rng
         B = state.batch
         if self.paged:
+            self.counters["ssd_state_inplace"] += self._ssd_inplace
             toks, lgs, h_per, v_per, carry = _decode_scan_paged(
                 self.params, self.cfg, state.cur, state.last, cache,
                 state.pos, rng, self.ucfg, int(max_new), bool(greedy),
@@ -1755,6 +1765,7 @@ class InferenceEngine:
                          for _, r in batcher.active() if r.return_state])
             dc["steps"] = turn.counts["steps"] = chunk
             if paged:
+                self.counters["ssd_state_inplace"] += self._ssd_inplace
                 cache = self._paged_dev_cache(slot_tables, slot_rows)
                 toks, _, h_per, v_per, carry = _decode_scan_paged(
                     self.params, self.cfg, cur, last, cache, pos, rng,

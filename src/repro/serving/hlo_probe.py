@@ -7,9 +7,17 @@ HLO type strings of every buffer the gathered view would create, lower the
 decode-scan executable, and scan its HLO text for them.  Used by
 ``tests/test_kernel_decode.py`` and enforced in CI through
 ``benchmarks/decode_microbench.py --check-hlo``.
+
+Likewise the kernel-first decode must step the SSD state where it lies:
+``whole_pool_writes`` lists the instructions of a compiled dispatch that
+write a whole buffer of the state pool's shape (a copy, a gather or
+scatter, a dynamic-update-slice or a fusion of one), which
+``tests/test_tpu_compile.py`` requires to be none.
 """
 
 from __future__ import annotations
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -70,3 +78,19 @@ def assert_no_slot_linear_kv(eng_gather, eng_kernel, prompts,
         f"{leaked}")
     return {"view_types": sorted(types_g), "in_gather_hlo": present,
             "in_kernel_hlo": leaked}
+
+
+# result producers that move no data: the pool passes through them as is
+_PASS_THROUGH = {"parameter", "get-tuple-element", "bitcast", "custom-call"}
+
+
+def whole_pool_writes(hlo_text: str, shape: tuple, dtype: str = "f32"
+                      ) -> list[str]:
+    """Instructions of a compiled HLO text whose result is a whole buffer
+    of ``dtype[shape]`` and that write it: every producer but a parameter,
+    a tuple element, a bitcast or a custom call (the in-place kernel,
+    aliased to its operand)."""
+    ty = re.escape(f"{dtype}[{','.join(map(str, shape))}]")
+    pat = re.compile(rf"= {ty}{{[^}}]*}} ([\w-]+)\(")
+    return [line.strip() for line in hlo_text.splitlines()
+            if (m := pat.search(line)) and m.group(1) not in _PASS_THROUGH]

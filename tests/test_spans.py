@@ -213,7 +213,7 @@ def paged_engine(model):
 
 @pytest.mark.parametrize("impl,want", [
     ("kernel", {"decode.uncertainty", "decode.model", "decode.sample",
-                "decode.cache_write"}),
+                "decode.cache_write", "decode.ssd_state"}),
     ("gather", {"decode.uncertainty", "decode.model", "decode.sample",
                 "decode.cache_gather", "decode.cache_write"})])
 def test_decode_program_names_its_phases(paged_engine, impl, want):

@@ -27,9 +27,11 @@ from repro.distributed import sharding as sh
 from repro.kernels.decode_attention.kernel import paged_decode_attention_pallas
 from repro.kernels.flash_attention.kernel import (
     flash_attention_positions_pallas)
+from repro.kernels.ssd_decode.kernel import ssd_state_update_pallas
 from repro.kernels.swarm_uncertainty.kernel import uncertainty_pallas
 from repro.models import transformer as T
 from repro.serving import engine as E
+from repro.serving.hlo_probe import whole_pool_writes
 
 ARCHS = ["smollm-135m", "swarm-edge-1b"]
 BLOCK_LEN = 64          # the engine's default pool block
@@ -185,3 +187,76 @@ def test_paged_decode_refuses_data_parallel_mesh(topo, one_chip,
     every device each step; the dispatch refuses to build instead."""
     with pytest.raises(NotImplementedError, match="shard-local pool blocks"):
         _decode_dispatch(topo, (2, 2), monkeypatch)
+
+
+def _ssd_geometry():
+    cfg = C.get_config("mamba2-780m")
+    H = cfg.ssm_d_inner // cfg.ssm_head_dim
+    return H, cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_ngroups
+
+
+def test_ssd_decode_compiles(one_chip):
+    """The SSD decode-state kernel at mamba2-780m's published state (48
+    layers, 32 rows of 48 heads x 64 x 128 f32): the pool is aliased in
+    place, so the program holds no second pool buffer."""
+    H, P, N, G = _ssd_geometry()
+    L, R, B = 48, 32, 32
+
+    def fn(pool, rows, layer, dA, dt, x, Bm, Cm, D):
+        return ssd_state_update_pallas(pool, rows, layer, dA, dt, x, Bm, Cm,
+                                       D, interpret=False)
+
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((L, R, H, P, N), jnp.float32), ((B,), jnp.int32), ((), jnp.int32),
+        ((B, H), jnp.float32), ((B, H), jnp.float32),
+        ((B, H, P), jnp.float32), ((B, G, N), jnp.float32),
+        ((B, G, N), jnp.float32), ((H,), jnp.float32))]
+    compiled = jax.jit(fn, donate_argnums=0).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    pool_bytes = L * R * H * P * N * 4
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes // L, mem
+
+
+def _ssd_dispatch(one_chip, monkeypatch, impl: str) -> str:
+    """Compiled text of the engine's paged decode dispatch (8 steps) for
+    mamba2-780m at published widths, cut to 4 layers, over 32 slots and 32
+    state rows (the benchmark cell's).  At 8 rows the 50 MB pool would fit
+    the chip's on-core memory and the compiler would stage it there, which
+    the full 2.4 GB pool never is."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(C.get_config("mamba2-780m"), num_layers=4)
+    B = R = 32
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    pool = placed(jax.eval_shape(
+        lambda: T.init_block_pool(cfg, 64, BLOCK_LEN, R)))
+    cache = T.paged_cache(pool, arg((B, 4), jnp.int32), arg((B,), jnp.int32))
+    return E._decode_scan_paged.lower(
+        placed(T.abstract_params(cfg)), cfg, arg((B,), jnp.int32),
+        arg((B, cfg.vocab_size), jnp.float32), cache, arg((B,), jnp.int32),
+        arg((2,), jnp.uint32), UncertaintyConfig(mode="distribution"), 8,
+        True, with_logits=False, impl=impl).compile().as_text()
+
+
+def test_ssd_decode_dispatch_steps_state_in_place(one_chip, monkeypatch):
+    """The kernel-first decode dispatch of mamba2-780m holds the SSD kernel
+    and writes no whole buffer of the f32 state pool's shape: no stacking
+    dynamic-update-slice, no gather or scatter of the rows, no layout copy.
+    The gathered path's dispatch, compiled alike, holds such writes, so the
+    probe finds what it looks for."""
+    H, P, N, _ = _ssd_geometry()
+    shape = (4, 32, H, P, N)
+    text = _ssd_dispatch(one_chip, monkeypatch, "kernel")
+    assert "tpu_custom_call" in text
+    assert not whole_pool_writes(text, shape), whole_pool_writes(text,
+                                                                 shape)[:2]
+    assert whole_pool_writes(_ssd_dispatch(one_chip, monkeypatch, "gather"),
+                             shape)
